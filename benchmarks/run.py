@@ -21,6 +21,8 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.utils.compile_cache import use_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -39,6 +41,7 @@ def main() -> None:
                          "guard (--quick already writes none; this also "
                          "covers the full/--fast suites)")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.quick:
         from benchmarks import (churn_bench, cluster_ablation, hier_bench,

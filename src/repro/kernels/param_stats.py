@@ -28,22 +28,27 @@ from jax.experimental import pallas as pl
 
 LANES = 128
 
-# out row layout: [sum(x-shift), sum((x-shift)^2), shift, unused]
-_OUT_W = 4
+# Per-client accumulator: one (8, 128) f32 tile, so the output block is
+# aligned to the TPU's (8, 128) tiling and every update is a whole-tile
+# vector op (Mosaic refuses scalar stores to VMEM). Rows hold per-lane
+# partial sums; the wrapper reduces the lanes:
+#   row 0: sum(x - shift)   row 1: sum((x - shift)^2)   row 2: shift
+_ACC_ROWS = 8
 
 
 def _stats_kernel(x_ref, out_ref, *, n_blocks, n_tail, inv_first):
     i = pl.program_id(1)
     x = x_ref[0].astype(jnp.float32)            # (block_rows, LANES)
     rows, lanes = x.shape
+    acc_row = jax.lax.broadcasted_iota(jnp.int32, (_ACC_ROWS, lanes), 0)
 
     @pl.when(i == 0)
     def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
         # shift = mean of the first block's real elements: zero padding
         # never perturbs the sum and inv_first normalises by the true
         # valid count, so the shift lands on the data's magnitude.
-        out_ref[0, 2] = jnp.sum(x) * inv_first
+        shift = jnp.sum(x, axis=(0, 1), keepdims=True) * inv_first
+        out_ref[0] = jnp.where(acc_row == 2, shift, 0.0)
 
     # Mask the tail padding: a padded zero would contribute (0 - shift)
     # to the shifted moments, and correcting that analytically in the
@@ -55,10 +60,13 @@ def _stats_kernel(x_ref, out_ref, *, n_blocks, n_tail, inv_first):
     idx_local = (jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) * lanes
                  + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1))
     valid = (i < n_blocks - 1) | (idx_local < n_tail)
-    shift = out_ref[0, 2]
+    acc = out_ref[0]                            # (_ACC_ROWS, LANES)
+    shift = acc[2:3, :]                         # (1, LANES), lanes equal
     d = jnp.where(valid, x - shift, 0.0)
-    out_ref[0, 0] += jnp.sum(d)
-    out_ref[0, 1] += jnp.sum(d * d)
+    sd = jnp.sum(d, axis=0, keepdims=True)      # (1, LANES) per-lane sums
+    ssd = jnp.sum(d * d, axis=0, keepdims=True)
+    out_ref[0] = acc + jnp.where(acc_row == 0, sd,
+                                 jnp.where(acc_row == 1, ssd, 0.0))
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -67,7 +75,7 @@ def param_stats_batched(x, *, block_rows=256, interpret=False):
 
     Returns two fp32 vectors of shape (N,). One pallas_call with grid
     (N, n_blocks): the block axis is innermost, so each client's
-    accumulator row is revisited sequentially (the standard revisited-
+    accumulator tile is revisited sequentially (the standard revisited-
     output reduction pattern).
     """
     N = x.shape[0]
@@ -93,12 +101,13 @@ def param_stats_batched(x, *, block_rows=256, interpret=False):
         kernel,
         grid=(N, n_blocks),
         in_specs=[pl.BlockSpec((1, block_rows, LANES), lambda b, i: (b, i, 0))],
-        out_specs=pl.BlockSpec((1, _OUT_W), lambda b, i: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((N, _OUT_W), jnp.float32),
+        out_specs=pl.BlockSpec((1, _ACC_ROWS, LANES), lambda b, i: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((N, _ACC_ROWS, LANES), jnp.float32),
         interpret=interpret,
     )(tiles)
 
-    sd, ssd, shift = out[:, 0], out[:, 1], out[:, 2]
+    sd, ssd = out[:, 0].sum(axis=1), out[:, 1].sum(axis=1)
+    shift = out[:, 2, 0]
     mean = shift + sd / n
     var = jnp.maximum(ssd / n - (sd / n) ** 2, 0.0)
     return mean, var

@@ -45,8 +45,10 @@ def collective_bytes(hlo_text: str) -> dict:
     pat = re.compile(
         r"=\s*(?:\()?([a-z0-9]+)\[([0-9,]*)\][^ ]*\s+(" + "|".join(_COLLECTIVES) + r")\(")
     # tuple-result collectives:  = (f32[8]{0}, f32[8]{0}) all-to-all(
+    # — greedy up to the op, since TPU layouts nest parentheses in the
+    # tuple: f32[12,32]{1,0:T(8,128)S(1)}
     tup = re.compile(
-        r"=\s*\(([^)]*)\)\s+(" + "|".join(_COLLECTIVES) + r")\(")
+        r"=\s*\((.*)\)\s+(" + "|".join(_COLLECTIVES) + r")\(")
     for line in hlo_text.splitlines():
         m = pat.search(line)
         if m:
@@ -72,6 +74,16 @@ def collective_bytes(hlo_text: str) -> dict:
     out["total"] = sum(out[c] for c in _COLLECTIVES)
     out["op_counts"] = n_ops
     return out
+
+
+def _cost(compiled):
+    """XLA's flops / bytes-accessed estimate of ``compiled``, or None
+    when the backend reports none."""
+    ca = compiled.cost_analysis()
+    ca = ca[0] if isinstance(ca, (list, tuple)) and ca else ca
+    if not ca:
+        return None
+    return {k: float(ca[k]) for k in ("flops", "bytes accessed") if k in ca}
 
 
 def fleet_round_comm(compiled, params_abs, n_clients: int,
@@ -102,36 +114,24 @@ def fleet_round_comm(compiled, params_abs, n_clients: int,
     * ``fedavg_bytes`` / ``blockchain_bytes`` — the server (2·N·P) and
       all-broadcast (N·(N−1)·P) baselines for the same model.
 
-    ``cost_analysis`` carries XLA's own flops / bytes-accessed estimate
-    when the backend provides one.
+    ``cost_analysis`` carries XLA's own flops / bytes-accessed estimate,
+    or None when the backend provides none.
     """
     up = upload_bytes(params_abs)
     full = full_params_bytes(params_abs)
-    try:
-        hlo = compiled.as_text()
-    except Exception:  # backend without HLO text dumps
-        hlo = ""
-    cost = {}
-    try:
-        ca = compiled.cost_analysis()
-        ca = ca[0] if isinstance(ca, (list, tuple)) else ca
-        cost = {k: float(ca[k]) for k in ("flops", "bytes accessed")
-                if k in ca}
-    except Exception:
-        pass
     return {
         "n_clients": n_clients,
         "stat_upload_bytes": n_clients * up,
         "val_upload_bytes": n_clients * 4,
         "cluster_feedback_bytes": n_clients * (4 + 4),
         "batch_upload_bytes": int(batch_bytes),
-        "eq2_collective_bytes": collective_bytes(hlo),
+        "eq2_collective_bytes": collective_bytes(compiled.as_text()),
         "eq2_p2p_bound_bytes": 2 * n_clients * full,
         "fedavg_bytes": 2 * n_clients * full,
         "blockchain_bytes": n_clients * (n_clients - 1) * full,
         "full_params_bytes": full,
         "coord_reduction_x": full / max(up, 1),
-        "cost_analysis": cost,
+        "cost_analysis": _cost(compiled),
     }
 
 
@@ -189,27 +189,15 @@ def hier_round_comm(compiled, params_abs, n_clients: int, *, n_pods: int,
     up only in ``cost_analysis``/``eq2_collective_bytes``.
     """
     full = full_params_bytes(params_abs)
-    try:
-        hlo = compiled.as_text()
-    except Exception:  # backend without HLO text dumps
-        hlo = ""
-    cost = {}
-    try:
-        ca = compiled.cost_analysis()
-        ca = ca[0] if isinstance(ca, (list, tuple)) else ca
-        cost = {k: float(ca[k]) for k in ("flops", "bytes accessed")
-                if k in ca}
-    except Exception:
-        pass
     out = hier_host_bytes(params_abs, n_clients, n_pods, k_local)
     out.update({
         "batch_upload_bytes": int(batch_bytes),
-        "eq2_collective_bytes": collective_bytes(hlo),
+        "eq2_collective_bytes": collective_bytes(compiled.as_text()),
         "eq2_p2p_bound_bytes": 2 * n_clients * full,
         "fedavg_bytes": 2 * n_clients * full,
         "blockchain_bytes": n_clients * (n_clients - 1) * full,
         "full_params_bytes": full,
-        "cost_analysis": cost,
+        "cost_analysis": _cost(compiled),
     })
     return out
 

@@ -33,9 +33,10 @@ identical driver code: ``make_unit_fleet`` + :func:`run_fleet` is both
 the tier-1 smoke and the traffic benchmark behind ``BENCH_fleet.json``
 (``python -m benchmarks.comm_scaling --fleet``).
 
-CLI::
+CLI (``--devices 8`` forces the 8-device CPU stand-in; without it the
+driver uses the backend's own devices and prints which it ran on)::
 
-    PYTHONPATH=src python -m repro.launch.fleet_driver --rounds 3
+    PYTHONPATH=src python -m repro.launch.fleet_driver --rounds 3 --devices 8
 """
 from __future__ import annotations
 
@@ -64,6 +65,7 @@ from repro.launch.swarm_fleet import fleet_setup, force_host_device_count
 from repro.models import build_model
 from repro.optim.optimizers import make_optimizer
 from repro.sharding import use_sharding
+from repro.utils.compile_cache import use_compile_cache
 
 # ------------------------------------------------------- host coordinator
 
@@ -730,9 +732,9 @@ def main():
     ap.add_argument("--data-scale", type=int, default=16)
     ap.add_argument("--image-size", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--devices", type=int, default=8,
-                    help="CPU stand-in device count (0 = leave backend "
-                         "alone)")
+    ap.add_argument("--devices", type=int, default=0,
+                    help="force this many CPU stand-in devices (0 = use "
+                         "the backend's own devices)")
     ap.add_argument("--pallas-stats", action="store_true")
     ap.add_argument("--eval-buckets", type=int, default=0,
                     help="bucket the val eval into at most this many "
@@ -763,6 +765,10 @@ def main():
     args = ap.parse_args()
     if args.devices:
         force_host_device_count(args.devices)
+    use_compile_cache()
+    dev = jax.devices()
+    print(f"[fleet] device: platform={dev[0].platform} "
+          f"kind={dev[0].device_kind} count={len(dev)}")
     model, opt, mesh, clients = make_unit_fleet(
         args.clients, image_size=args.image_size,
         data_scale=args.data_scale, seed=args.seed)

@@ -150,8 +150,6 @@ def fleet_setup(model, opt, mesh, *, k: int, n_local_steps: int = 1,
         raise ValueError("hier_k_local selects its own eval surface — "
                          "drop with_eval/with_loss")
     if spmd == "shard_map":
-        from jax.experimental.shard_map import shard_map
-        from repro.sharding import use_sharding
         inner_step = make_fleet_round(model, opt, k, n_local_steps,
                                       use_pallas=use_pallas_stats,
                                       with_eval=with_eval,
@@ -195,10 +193,11 @@ def fleet_setup(model, opt, mesh, *, k: int, n_local_steps: int = 1,
             # present, agg_present (+ report on the hier surface)
             in_specs = in_specs + ((pod, pod, pod) if hier
                                    else (pod, pod))
-        # check_rep off: several conv/reduce-window primitives lack
-        # replication rules in this jax version
-        round_step = shard_map(local_step, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=False)
+        # check_vma off: several conv/reduce-window primitives lack
+        # varying-manual-axes rules
+        round_step = jax.shard_map(local_step, mesh=mesh,
+                                   in_specs=in_specs, out_specs=out_specs,
+                                   check_vma=False)
         to_shard = lambda spec: rep if spec == P() else ssh
         in_sh = jax.tree.map(to_shard, in_specs,
                              is_leaf=lambda x: isinstance(x, P))

@@ -15,12 +15,6 @@ from repro.core.kmeans import assign, kmeans, lloyd_step
 
 KEY = jax.random.PRNGKey(0)
 
-# jax.shard_map only exists on newer jax; fall back to the experimental
-# location (the API is identical for our usage)
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:
-    from jax.experimental.shard_map import shard_map
-
 
 # ---------------------------------------------------------------- diststats
 
@@ -236,9 +230,9 @@ def test_cluster_psum_fedavg_single_client_mesh():
         out = cluster_psum_fedavg(inner, w[0], c[0], 3, "pod")
         return jax.tree.map(lambda x: x[None], out)
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P("pod"), P("pod"), P("pod")),
-                   out_specs=P("pod"))
+    fn = jax.shard_map(body, mesh=mesh, check_vma=False,
+                       in_specs=(P("pod"), P("pod"), P("pod")),
+                       out_specs=P("pod"))
     out = fn(params, jnp.asarray([2.0]), jnp.asarray([1], jnp.int32))
     np.testing.assert_allclose(np.asarray(out["w"]), np.asarray(params["w"]))
 
@@ -263,9 +257,9 @@ def test_cluster_fedavg_matches_psum_fedavg_shard_map():
         out = cluster_psum_fedavg(inner, w[0], c[0], k, "pod")
         return jax.tree.map(lambda x: x[None], out)
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P("pod"), P("pod"), P("pod")),
-                   out_specs=P("pod"))
+    fn = jax.shard_map(body, mesh=mesh, check_vma=False,
+                       in_specs=(P("pod"), P("pod"), P("pod")),
+                       out_specs=P("pod"))
     got = fn(stacked, weights, assignments)
     for key in ("w", "b"):
         np.testing.assert_allclose(np.asarray(got[key]),

@@ -7,9 +7,15 @@ host devices — harmless here because jax is already initialised with
 1 device by earlier imports in the pytest process; nothing in these
 tests builds the production mesh.
 """
+import os
+import subprocess
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 
 def _dryrun():
@@ -24,15 +30,50 @@ def test_collective_bytes_parser():
   %tup = (f32[8]{0}, f32[8]{0}) all-to-all(f32[8]{0} %a, f32[8]{0} %b)
   %cp = u32[4]{0} collective-permute(u32[4]{0} %c)
   %not_a_collective = f32[999]{0} add(f32[999]{0} %p, f32[999]{0} %q)
+  %tpu = (f32[12,32]{1,0:T(8,128)S(1)}, f32[4]{0:T(128)}) all-reduce(f32[12,32]{1,0:T(8,128)S(1)} %d, f32[4]{0} %e), metadata={op_name="psum"}
 """
     out = _dryrun().collective_bytes(hlo)
     assert out["all-gather"] == 128 * 256 * 2
-    assert out["all-reduce"] == 1024 * 4
+    # TPU tile layouts nest parentheses inside a tuple result
+    assert out["all-reduce"] == 1024 * 4 + (12 * 32 + 4) * 4
     assert out["all-to-all"] == 2 * 8 * 4
     assert out["collective-permute"] == 4 * 4
     assert out["total"] == sum(out[k] for k in
                                ("all-gather", "all-reduce", "reduce-scatter",
                                 "all-to-all", "collective-permute"))
+
+
+_CACHE_PROBE = """
+import jax, jax.numpy as jnp
+from repro.utils.compile_cache import use_compile_cache
+print(use_compile_cache())
+print(jax.config.jax_compilation_cache_dir)
+if {compile}:
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.jit(lambda x: jnp.sin(x) * 3).lower(jnp.ones(7)).compile()
+"""
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(tmp_path, from_env):
+    """$JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; without
+    it the cache sits at the fixed ``<checkout>/.jax_cache``. Runs in a
+    child so this process's jax config is left alone."""
+    from repro.utils.compile_cache import CHECKOUT_CACHE_DIR
+    root = Path(__file__).resolve().parents[1]
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=str(root / "src"))
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    out = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE.format(compile=from_env)],
+        env=env, capture_output=True, text=True, check=True).stdout.split()
+    want = str(tmp_path) if from_env else str(root / ".jax_cache")
+    assert out == [want, want]
+    assert CHECKOUT_CACHE_DIR == root / ".jax_cache"
+    if from_env:                  # the compile landed in the env's dir
+        assert any(tmp_path.iterdir())
 
 
 def test_microbatch_divisibility_guard():

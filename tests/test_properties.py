@@ -187,7 +187,6 @@ def test_cluster_fedavg_psum_masked_matches_segment_sum(n, k, seed, drop):
     """Fleet-regime masked psum Eq. 2 == sim-regime masked segment-sum
     on a 1-device 'pod' mesh (whole swarm in one shard; the psum is the
     identity reduction, so any divergence is in the shared math)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     W, a, _, weights, present = _masked_case(n, k, seed, drop_frac=drop)
     expect = cluster_fedavg_masked({"w": jnp.asarray(W)}, jnp.asarray(a),
@@ -200,9 +199,9 @@ def test_cluster_fedavg_psum_masked_matches_segment_sum(n, k, seed, drop):
         out = cluster_fedavg_psum_masked(inner, c[0], w[0], m[0], k, "pod")
         return jax.tree.map(lambda x: x[None], out)
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P("pod"), P("pod"), P("pod"), P("pod")),
-                   out_specs=P("pod"))
+    fn = jax.shard_map(body, mesh=mesh, check_vma=False,
+                       in_specs=(P("pod"), P("pod"), P("pod"), P("pod")),
+                       out_specs=P("pod"))
     got = fn({"w": jnp.asarray(W)[None]}, jnp.asarray(a)[None],
              jnp.asarray(weights)[None], jnp.asarray(present)[None])["w"][0]
     np.testing.assert_allclose(np.asarray(got), np.asarray(expect),

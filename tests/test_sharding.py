@@ -87,7 +87,8 @@ def test_shard_act_noop_without_context():
 
 def test_shard_act_applies_constraint_under_mesh():
     from repro.sharding import shard_act, use_sharding
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
     @jax.jit
     def f(x):
