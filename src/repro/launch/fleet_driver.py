@@ -243,6 +243,7 @@ class FleetRunResult:
     params: Any                        # final client-stacked params (on mesh)
     compile_s: float = 0.0
     meta: dict = field(default_factory=dict)
+    compiled: Any = None               # the round executable (its HLO)
 
     @property
     def mean_val_accs(self):
@@ -720,7 +721,8 @@ def run_fleet(model, opt, mesh, clients_data, *, rounds: int,
     n_compiles = (1 + program.jit_fn._cache_size()
                   + sum(fn._cache_size() for _, _, fn in eval_progs))
     return FleetRunResult(history=history, n_compiles=n_compiles, comm=comm,
-                          params=sparams, compile_s=compile_s, meta=meta)
+                          params=sparams, compile_s=compile_s, meta=meta,
+                          compiled=compiled)
 
 
 def main():
