@@ -52,19 +52,20 @@ def cluster_fedavg(stacked_params, assignments, n_samples, k: int):
     Returns the stacked pytree where client i holds its cluster's
     aggregated model (the redistribution step).
     """
-    assignments = jnp.asarray(assignments)
-    w = jnp.asarray(n_samples, jnp.float32)
-    # per-cluster weight normalisation: |D_h| / |D_{G_k}|
-    cluster_tot = jax.ops.segment_sum(w, assignments, num_segments=k)
-    wn = w / jnp.maximum(cluster_tot[assignments], 1e-9)
+    with jax.named_scope("bso.eq2"):
+        assignments = jnp.asarray(assignments)
+        w = jnp.asarray(n_samples, jnp.float32)
+        # per-cluster weight normalisation: |D_h| / |D_{G_k}|
+        cluster_tot = jax.ops.segment_sum(w, assignments, num_segments=k)
+        wn = w / jnp.maximum(cluster_tot[assignments], 1e-9)
 
-    def agg_leaf(leaf):
-        lf = leaf.astype(jnp.float32)
-        weighted = lf * wn.reshape((-1,) + (1,) * (lf.ndim - 1))
-        sums = jax.ops.segment_sum(weighted, assignments, num_segments=k)
-        return sums[assignments].astype(leaf.dtype)
+        def agg_leaf(leaf):
+            lf = leaf.astype(jnp.float32)
+            weighted = lf * wn.reshape((-1,) + (1,) * (lf.ndim - 1))
+            sums = jax.ops.segment_sum(weighted, assignments, num_segments=k)
+            return sums[assignments].astype(leaf.dtype)
 
-    return jax.tree.map(agg_leaf, stacked_params)
+        return jax.tree.map(agg_leaf, stacked_params)
 
 
 def cluster_fedavg_masked(stacked_params, assignments, weights, present,
@@ -98,23 +99,24 @@ def cluster_fedavg_masked(stacked_params, assignments, weights, present,
     |D_h| keep every cluster total strictly positive —
     ``tests/test_churn.py`` pins the equivalence.
     """
-    assignments = jnp.asarray(assignments)
-    w = jnp.asarray(weights, jnp.float32)
-    present = jnp.asarray(present, bool)
-    cluster_tot = jax.ops.segment_sum(w, assignments, num_segments=k)
-    wn = w / jnp.maximum(cluster_tot[assignments], 1e-9)
-    # receive = participated AND the cluster actually aggregated
-    take = present & (cluster_tot[assignments] > 0.0)
+    with jax.named_scope("bso.eq2"):
+        assignments = jnp.asarray(assignments)
+        w = jnp.asarray(weights, jnp.float32)
+        present = jnp.asarray(present, bool)
+        cluster_tot = jax.ops.segment_sum(w, assignments, num_segments=k)
+        wn = w / jnp.maximum(cluster_tot[assignments], 1e-9)
+        # receive = participated AND the cluster actually aggregated
+        take = present & (cluster_tot[assignments] > 0.0)
 
-    def agg_leaf(leaf):
-        lf = leaf.astype(jnp.float32)
-        weighted = lf * wn.reshape((-1,) + (1,) * (lf.ndim - 1))
-        sums = jax.ops.segment_sum(weighted, assignments, num_segments=k)
-        agg = sums[assignments].astype(leaf.dtype)
-        m = take.reshape((-1,) + (1,) * (leaf.ndim - 1))
-        return jnp.where(m, agg, leaf)
+        def agg_leaf(leaf):
+            lf = leaf.astype(jnp.float32)
+            weighted = lf * wn.reshape((-1,) + (1,) * (lf.ndim - 1))
+            sums = jax.ops.segment_sum(weighted, assignments, num_segments=k)
+            agg = sums[assignments].astype(leaf.dtype)
+            m = take.reshape((-1,) + (1,) * (leaf.ndim - 1))
+            return jnp.where(m, agg, leaf)
 
-    return jax.tree.map(agg_leaf, stacked_params)
+        return jax.tree.map(agg_leaf, stacked_params)
 
 
 def cluster_fedavg_psum(stacked_params, assignments, n_samples, k: int,

@@ -116,54 +116,55 @@ def brain_storm_jax(key, assignments, val_scores, k: int, p1, p2):
     empty cluster), and the round's event counts (replacing the numpy
     version's event strings — the only host-facing residue).
     """
-    a = jnp.asarray(assignments, jnp.int32)
-    val = jnp.asarray(val_scores, jnp.float32)
-    member = a[None, :] == jnp.arange(k, dtype=jnp.int32)[:, None]   # (k, N)
-    occupied = jnp.any(member, axis=1)                               # (k,)
-    n_occ = jnp.sum(occupied.astype(jnp.int32))
+    with jax.named_scope("bso.brain_storm"):
+        a = jnp.asarray(assignments, jnp.int32)
+        val = jnp.asarray(val_scores, jnp.float32)
+        member = a[None, :] == jnp.arange(k, dtype=jnp.int32)[:, None]   # (k, N)
+        occupied = jnp.any(member, axis=1)                               # (k,)
+        n_occ = jnp.sum(occupied.astype(jnp.int32))
 
-    # 1. centers = best validation score per cluster (masked argmax)
-    centers = jnp.argmax(jnp.where(member, val[None, :], -jnp.inf),
-                         axis=1).astype(jnp.int32)
-    centers = jnp.where(occupied, centers, -1)
-
-    k_rep, k_member, k_swap, k_other = jax.random.split(key, 4)
-    cluster_ids = jnp.arange(k, dtype=jnp.uint32)
-
-    # 2a. random center replacement (r1 > p1): a uniformly random member
-    # per cluster via masked Gumbel-argmax (one draw per (cluster,
-    # client), no data-dependent shapes)
-    r1 = jax.vmap(lambda c: jax.random.uniform(
-        jax.random.fold_in(k_rep, c)))(cluster_ids)
-    g = jax.vmap(lambda c: jax.random.gumbel(
-        jax.random.fold_in(k_member, c), (a.shape[0],)))(cluster_ids)
-    rand_member = jnp.argmax(jnp.where(member, g, -jnp.inf),
+        # 1. centers = best validation score per cluster (masked argmax)
+        centers = jnp.argmax(jnp.where(member, val[None, :], -jnp.inf),
                              axis=1).astype(jnp.int32)
-    do_rep = (r1 > p1) & occupied
-    n_replaced = jnp.sum((do_rep & (rand_member != centers)).astype(jnp.int32))
-    centers = jnp.where(do_rep, rand_member, centers)
+        centers = jnp.where(occupied, centers, -1)
 
-    # 2b. sequential cross-cluster center swaps (r2 > p2). Later swaps
-    # must see earlier ones (same as the host loop), so unroll over the
-    # static k; the swap partner is a uniformly random *other* occupied
-    # cluster via masked Gumbel-argmax. The partner gumbels are drawn
-    # per (c, other) pair so pad slots never perturb the real pairs.
-    r2 = jax.vmap(lambda c: jax.random.uniform(
-        jax.random.fold_in(k_swap, c)))(cluster_ids)
-    g2 = jax.vmap(lambda c: jax.vmap(lambda o: jax.random.gumbel(
-        jax.random.fold_in(jax.random.fold_in(k_other, c), o)))(
-            cluster_ids))(cluster_ids)
-    n_swapped = jnp.zeros((), jnp.int32)
-    for c in range(k):
-        valid_other = occupied & (jnp.arange(k) != c)
-        other = jnp.argmax(jnp.where(valid_other, g2[c], -jnp.inf)
-                           ).astype(jnp.int32)
-        do_swap = (r2[c] > p2) & occupied[c] & (n_occ > 1)
-        ci, oi = centers[c], centers[other]
-        swapped_centers = centers.at[c].set(oi).at[other].set(ci)
-        swapped_a = a.at[ci].set(a[oi]).at[oi].set(a[ci])
-        centers = jnp.where(do_swap, swapped_centers, centers)
-        a = jnp.where(do_swap, swapped_a, a)
-        n_swapped = n_swapped + do_swap.astype(jnp.int32)
+        k_rep, k_member, k_swap, k_other = jax.random.split(key, 4)
+        cluster_ids = jnp.arange(k, dtype=jnp.uint32)
 
-    return a, centers, n_replaced, n_swapped
+        # 2a. random center replacement (r1 > p1): a uniformly random member
+        # per cluster via masked Gumbel-argmax (one draw per (cluster,
+        # client), no data-dependent shapes)
+        r1 = jax.vmap(lambda c: jax.random.uniform(
+            jax.random.fold_in(k_rep, c)))(cluster_ids)
+        g = jax.vmap(lambda c: jax.random.gumbel(
+            jax.random.fold_in(k_member, c), (a.shape[0],)))(cluster_ids)
+        rand_member = jnp.argmax(jnp.where(member, g, -jnp.inf),
+                                 axis=1).astype(jnp.int32)
+        do_rep = (r1 > p1) & occupied
+        n_replaced = jnp.sum((do_rep & (rand_member != centers)).astype(jnp.int32))
+        centers = jnp.where(do_rep, rand_member, centers)
+
+        # 2b. sequential cross-cluster center swaps (r2 > p2). Later swaps
+        # must see earlier ones (same as the host loop), so unroll over the
+        # static k; the swap partner is a uniformly random *other* occupied
+        # cluster via masked Gumbel-argmax. The partner gumbels are drawn
+        # per (c, other) pair so pad slots never perturb the real pairs.
+        r2 = jax.vmap(lambda c: jax.random.uniform(
+            jax.random.fold_in(k_swap, c)))(cluster_ids)
+        g2 = jax.vmap(lambda c: jax.vmap(lambda o: jax.random.gumbel(
+            jax.random.fold_in(jax.random.fold_in(k_other, c), o)))(
+                cluster_ids))(cluster_ids)
+        n_swapped = jnp.zeros((), jnp.int32)
+        for c in range(k):
+            valid_other = occupied & (jnp.arange(k) != c)
+            other = jnp.argmax(jnp.where(valid_other, g2[c], -jnp.inf)
+                               ).astype(jnp.int32)
+            do_swap = (r2[c] > p2) & occupied[c] & (n_occ > 1)
+            ci, oi = centers[c], centers[other]
+            swapped_centers = centers.at[c].set(oi).at[other].set(ci)
+            swapped_a = a.at[ci].set(a[oi]).at[oi].set(a[ci])
+            centers = jnp.where(do_swap, swapped_centers, centers)
+            a = jnp.where(do_swap, swapped_a, a)
+            n_swapped = n_swapped + do_swap.astype(jnp.int32)
+
+        return a, centers, n_replaced, n_swapped

@@ -89,7 +89,8 @@ def swarm_distribution_matrix(stacked_params, n_clients: int = None, *,
             raise ValueError(
                 f"stacked_params has client axis {lead} but n_clients="
                 f"{n_clients}; slice the pytree to the requested subset")
-    return _swarm_features(stacked_params, use_pallas=use_pallas)
+    with jax.named_scope("bso.stat_upload"):
+        return _swarm_features(stacked_params, use_pallas=use_pallas)
 
 
 def swarm_distribution_matrix_loop(stacked_params, n_clients: int, *,

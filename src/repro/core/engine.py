@@ -858,8 +858,9 @@ def local_phase(step, params, opt_state, lr, xs, batch_for_step, *,
         return (p2, o2), loss
 
     n_steps = jax.tree.leaves(xs)[0].shape[0]
-    (params, opt_state), losses = jax.lax.scan(
-        body, (params, opt_state), (jnp.arange(n_steps), xs), unroll=unroll)
+    with jax.named_scope("bso.local_phase"):
+        (params, opt_state), losses = jax.lax.scan(
+            body, (params, opt_state), (jnp.arange(n_steps), xs), unroll=unroll)
     return params, opt_state, losses
 
 
@@ -897,16 +898,17 @@ def eval_swarm(model: Model, params, data):
     accumulator (``accuracy`` masks label=-1 rows and divides by
     ``max(valid, 1)``).
     """
-    ev = make_client_eval(model)
-    if isinstance(data, BucketedSwarmData):
-        N = data.train_n.shape[0]
-        acc = jnp.zeros((N,), jnp.float32)
-        for ids, val_b in zip(data.client_ids, data.val):
-            ids_arr = np.asarray(ids)
-            sub = jax.tree.map(lambda x: x[ids_arr], params)
-            acc = acc.at[ids_arr].set(ev(sub, val_b))
-        return acc
-    return ev(params, data.val)
+    with jax.named_scope("bso.eval"):
+        ev = make_client_eval(model)
+        if isinstance(data, BucketedSwarmData):
+            N = data.train_n.shape[0]
+            acc = jnp.zeros((N,), jnp.float32)
+            for ids, val_b in zip(data.client_ids, data.val):
+                ids_arr = np.asarray(ids)
+                sub = jax.tree.map(lambda x: x[ids_arr], params)
+                acc = acc.at[ids_arr].set(ev(sub, val_b))
+            return acc
+        return ev(params, data.val)
 
 
 # ---------------------------------------------------------------- the round

@@ -224,10 +224,11 @@ def kmeans(key, X, k: int, iters: int = 20, *, use_pallas: bool = False,
     ``weights`` their member counts (the hierarchical coordinator's
     global tier). ``weights=None`` is bitwise the unweighted run;
     composes multiplicatively with ``mask``."""
-    C0 = kmeans_pp_init(key, X, k, mask=mask, weights=weights)
-    C = jax.lax.fori_loop(
-        0, iters,
-        lambda it, C: lloyd_step(X, C, k, use_pallas=use_pallas,
-                                 k_active=k_active, mask=mask,
-                                 weights=weights), C0)
-    return C, _assign_fn(use_pallas, k_active)(X, C)
+    with jax.named_scope("bso.kmeans"):
+        C0 = kmeans_pp_init(key, X, k, mask=mask, weights=weights)
+        C = jax.lax.fori_loop(
+            0, iters,
+            lambda it, C: lloyd_step(X, C, k, use_pallas=use_pallas,
+                                     k_active=k_active, mask=mask,
+                                     weights=weights), C0)
+        return C, _assign_fn(use_pallas, k_active)(X, C)

@@ -139,15 +139,19 @@ class SwarmTrainer:
         return float(self.client_scores(split).mean())
 
     # ---------------------------------------------------------------- round
-    def round(self, r: int, key) -> RoundLog:
-        """One protocol round == one engine program dispatch."""
-        # the engine donates its state buffers; copy the caller's key so
-        # their array survives the donation (keys are reusable here)
-        state = self.state._replace(key=jnp.copy(key))
-        self.state, m = jit_swarm_round(state, self.swarm_data,
-                                        self.engine_cfg)
-        log = _round_log(r, m)
-        self.history.append(log)
+    def round(self, r: int, key=None) -> RoundLog:
+        """Round ``r``, one engine dispatch; ``key`` starts a key chain
+        (None continues the state's). Traced as the host span ``bso.round``
+        (step ``r``) holding ``bso.dispatch`` and ``bso.round_log``."""
+        with jax.profiler.StepTraceAnnotation("bso.round", step_num=r):
+            if key is not None:   # copied: the engine donates the state
+                self.state = self.state._replace(key=jnp.copy(jnp.asarray(key)))
+            with jax.profiler.TraceAnnotation("bso.dispatch"):
+                self.state, m = jit_swarm_round(self.state, self.swarm_data,
+                                                self.engine_cfg)
+            with jax.profiler.TraceAnnotation("bso.round_log"):
+                log = _round_log(r, m)
+            self.history.append(log)
         return log
 
     def fit(self, key, rounds: Optional[int] = None, verbose: bool = False):
@@ -157,13 +161,9 @@ class SwarmTrainer:
         :meth:`fit_scanned`'s scan advances, so the two are bitwise
         interchangeable (``tests/test_sweep.py`` pins this)."""
         rounds = rounds or self.swarm.rounds
-        self.state = self.state._replace(key=jnp.copy(jnp.asarray(key)))
         start = len(self.history)
         for r in range(start, start + rounds):
-            self.state, m = jit_swarm_round(self.state, self.swarm_data,
-                                            self.engine_cfg)
-            log = _round_log(r, m)
-            self.history.append(log)
+            log = self.round(r, key if r == start else None)
             if verbose:
                 print(f"[{self.aggregation}] round {r:3d} "
                       f"val_acc={log.mean_val_acc:.4f} loss={log.train_loss:.4f} "
