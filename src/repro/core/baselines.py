@@ -72,8 +72,9 @@ def make_method_setup(model: Model, clients_data, swarm: SwarmConfig,
     the data across architectures the same way).
 
     ``layout`` picks the device data layout when ``data`` is built
-    here: ``"rect"`` is the pad-to-global-max
-    :class:`~repro.core.engine.SwarmData`, ``"bucketed"`` the ragged
+    here: ``"rect"`` is :class:`~repro.core.engine.SwarmData` (train
+    padded to the global max, val grouped by microbatch count),
+    ``"bucketed"`` the ragged
     :class:`~repro.core.engine.BucketedSwarmData` (size-bucketed pads;
     bitwise the same results — see ``tests/test_bucket.py``)."""
     if cfg is None:

@@ -77,7 +77,8 @@ Contract summary (the stable public surface):
 * :class:`SwarmState` — the complete mutable swarm (params, opt state,
   PRNG key, round counter, Eq. 2 sample weights), one pytree.
 * :class:`SwarmData` — the device-resident fixed-shape dataset
-  (padded train stack + sampling bounds + masked eval stacks).
+  (padded train stack + sampling bounds + masked val stacks, one per
+  group of clients that need the same number of eval microbatches).
 * :class:`EngineConfig` — the static (hashable) round configuration;
   equal configs share one compiled program.
 * :class:`MethodParams` / :class:`GridPoint` — traced per-row axes:
@@ -129,18 +130,38 @@ class SwarmState(NamedTuple):
     #                                  predating the churn engine
 
 
-class SwarmData(NamedTuple):
+@jax.tree_util.register_pytree_node_class
+class SwarmData:
     """Device-resident, fixed-shape swarm dataset.
 
     train:   batch pytree with shape (N, n_max, ...); clients shorter
              than n_max are padded (pad rows are never sampled).
     train_n: (N,) int32 true train-set sizes — the sampling bound.
-    val:     client-stacked eval batches (N, n_batches, batch, ...)
-             with label=-1 masking (see :func:`stack_eval_split`).
+    val:     tuple of val groups, group g the clients that need the
+             same number of eval microbatches, stacked (N_g, n_batches_g,
+             batch, ...) with label=-1 masking (see
+             :func:`stack_eval_split`), so no client carries an all-pad
+             microbatch.
+    val_ids: static tuple of per-group client-id tuples (ascending
+             within a group; a partition of range(N)). Pytree aux data,
+             the contract of :attr:`BucketedSwarmData.client_ids`: equal
+             layouts share one compiled program. A swarm whose clients
+             all need the same count is one group, ``range(N)``.
     """
-    train: Any
-    train_n: Any
-    val: Any
+
+    def __init__(self, train, train_n, val, val_ids):
+        self.train = train
+        self.train_n = train_n
+        self.val = tuple(val)
+        self.val_ids = tuple(tuple(int(i) for i in ids) for ids in val_ids)
+
+    def tree_flatten(self):
+        return (self.train, self.train_n, self.val), self.val_ids
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        train, train_n, val = children
+        return cls(train, train_n, val, aux)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -187,6 +208,11 @@ class BucketedSwarmData:
     @property
     def n_buckets(self) -> int:
         return len(self.client_ids)
+
+    @property
+    def val_ids(self):
+        """The val groups are the buckets (:attr:`SwarmData.val_ids`)."""
+        return self.client_ids
 
     def tree_flatten(self):
         return (self.train, self.val, self.train_n), self.client_ids
@@ -559,7 +585,9 @@ def make_swarm_data(cfg: ModelConfig, clients_data, *,
     """Build the device-resident :class:`SwarmData` from the per-clinic
     host dicts. Train sets are padded to the largest client with
     label=-1 poison rows; ``train_n`` bounds the on-device sampler so
-    pads are never drawn."""
+    pads are never drawn. Val splits are grouped by the number of
+    ``eval_batch``-row microbatches each needs (groups in ascending
+    count), each group stacked by :func:`stack_eval_split`."""
     n_max = max(len(c["train"][1]) for c in clients_data)
     Xs, ys = [], []
     for c in clients_data:
@@ -569,9 +597,12 @@ def make_swarm_data(cfg: ModelConfig, clients_data, *,
     train = make_batch(cfg, np.stack(Xs), np.stack(ys))
     train_n = jnp.asarray([len(c["train"][1]) for c in clients_data],
                           jnp.int32)
-    return SwarmData(train=train, train_n=train_n,
-                     val=stack_eval_split(cfg, clients_data, "val",
-                                          batch=eval_batch))
+    n_batches = [-(-len(c["val"][1]) // eval_batch) for c in clients_data]
+    val_ids = [tuple(i for i, n in enumerate(n_batches) if n == m)
+               for m in sorted(set(n_batches))]
+    val = [stack_eval_split(cfg, [clients_data[i] for i in ids], "val",
+                            batch=eval_batch) for ids in val_ids]
+    return SwarmData(train=train, train_n=train_n, val=val, val_ids=val_ids)
 
 
 def make_bucketed_swarm_data(cfg: ModelConfig, clients_data, *,
@@ -610,15 +641,13 @@ def pad_fraction(data) -> dict:
     stored train/eval rows that are padding — the waste metric
     ``BENCH_bucket.json`` quantifies. Returns ``{"train": f, "eval": f,
     "total": f, "stored_rows": n, "real_rows": n}``."""
-    if isinstance(data, BucketedSwarmData):
-        trains, vals = data.train, data.val
-    else:
-        trains, vals = (data.train,), (data.val,)
+    trains = (data.train if isinstance(data, BucketedSwarmData)
+              else (data.train,))
     tr_stored = sum(int(np.prod(jax.tree.leaves(t)[0].shape[:2]))
                     for t in trains)
     tr_real = int(np.sum(np.asarray(data.train_n)))
     ev_stored = ev_real = 0
-    for v in vals:
+    for v in data.val:
         labels = np.asarray(v["labels"])
         ev_stored += labels.size
         ev_real += int((labels >= 0).sum())
@@ -885,30 +914,31 @@ def make_client_eval(model: Model):
 
 
 def eval_swarm(model: Model, params, data):
-    """Layout-dispatching per-client val accuracy — the masked segment
-    reduction over whichever stacks ``data`` carries.
+    """Per-client val accuracy — the masked segment reduction over the
+    val groups of either layout (:attr:`SwarmData.val_ids`, the buckets
+    of :class:`BucketedSwarmData`).
 
-    Rectangular: the one vmapped :func:`make_client_eval` program.
-    Bucketed: one fixed-shape vmapped eval per bucket (same static-
-    shape discipline as :func:`run_grid` — equal bucket signatures
-    share the trace), client accuracies scattered back to global
-    order. BITWISE the rectangular result: a bucket's stack is a
-    microbatch-prefix of the rectangular stack, and every dropped
-    all-pad microbatch contributed exactly +0.0 to the (hits, total)
-    accumulator (``accuracy`` masks label=-1 rows and divides by
-    ``max(valid, 1)``).
+    One fixed-shape vmapped :func:`make_client_eval` program per group
+    (same static-shape discipline as :func:`run_grid` — equal group
+    signatures share the trace), client accuracies scattered back to
+    global order; a single ``range(N)`` group is evaluated as it
+    stands, with no gather or scatter. BITWISE the one rectangular
+    stack's result: a group's stack is a microbatch-prefix of the
+    rectangular stack, and every dropped all-pad microbatch contributed
+    exactly +0.0 to the (hits, total) accumulator (``accuracy`` masks
+    label=-1 rows and divides by ``max(valid, 1)``).
     """
     with jax.named_scope("bso.eval"):
         ev = make_client_eval(model)
-        if isinstance(data, BucketedSwarmData):
-            N = data.train_n.shape[0]
-            acc = jnp.zeros((N,), jnp.float32)
-            for ids, val_b in zip(data.client_ids, data.val):
-                ids_arr = np.asarray(ids)
-                sub = jax.tree.map(lambda x: x[ids_arr], params)
-                acc = acc.at[ids_arr].set(ev(sub, val_b))
-            return acc
-        return ev(params, data.val)
+        N = data.train_n.shape[0]
+        if data.val_ids == (tuple(range(N)),):
+            return ev(params, data.val[0])
+        acc = jnp.zeros((N,), jnp.float32)
+        for ids, val_g in zip(data.val_ids, data.val):
+            ids_arr = np.asarray(ids)
+            sub = jax.tree.map(lambda x: x[ids_arr], params)
+            acc = acc.at[ids_arr].set(ev(sub, val_g))
+        return acc
 
 
 # ---------------------------------------------------------------- the round

@@ -22,6 +22,7 @@ conversion of per-round metrics into ``RoundLog``.
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Dict, List, Optional
 
@@ -31,7 +32,7 @@ import numpy as np
 
 from repro.configs.base import OptimizerConfig, SwarmConfig
 from repro.core.engine import (EngineConfig, RoundMetrics, SwarmState,
-                               jit_run_rounds, jit_swarm_round,
+                               eval_swarm, jit_run_rounds, jit_swarm_round,
                                make_batch, make_client_eval, make_swarm_data,
                                make_swarm_state, pad_eval_split,
                                resolve_local_steps, stack_eval_split)
@@ -100,14 +101,20 @@ class SwarmTrainer:
         self.swarm_data = make_swarm_data(self.cfg, clients_data)
         self.state: SwarmState = make_swarm_state(model, self.opt,
                                                   clients_data, key)
+        # static arguments of the bso.round span: the val slots eval
+        # scores a round, and how many of them are real images
+        self._round_args = {
+            "eval_rows": sum(math.prod(v["labels"].shape[:3])
+                             for v in self.swarm_data.val),
+            "eval_real_rows": sum(len(c["val"][1]) for c in clients_data)}
 
         # _eval stays public-ish: eval_client(tr._eval, ...) is the
         # per-client parity oracle used by tests and coordinator_bench
         self._eval = jax.jit(make_eval_step(model))
         self._veval = jax.jit(make_client_eval(model))
-        # the engine data already holds the device-resident val stack;
-        # seed the split cache so client_scores("val") reuses it
-        self._eval_splits: Dict[str, dict] = {"val": self.swarm_data.val}
+        # val is scored as the round scores it, over the engine's groups
+        self._eval_val = jax.jit(lambda p, d: eval_swarm(model, p, d))
+        self._eval_splits: Dict[str, dict] = {}
         self.history: List[RoundLog] = []
 
     # engine state passthroughs (the state pytree is the truth)
@@ -127,7 +134,11 @@ class SwarmTrainer:
     def client_scores(self, split: str = "val") -> np.ndarray:
         """Per-client masked accuracy — ONE vmapped device program over
         the client axis per split (eval data is static, so the
-        device-resident stack is built once per split)."""
+        device-resident stack is built once per split); ``"val"`` is
+        the engine's own eval over :attr:`swarm_data`'s val groups."""
+        if split == "val":
+            scores = self._eval_val(self.state.params, self.swarm_data)
+            return np.asarray(scores, np.float32)
         if split not in self._eval_splits:
             self._eval_splits[split] = stack_eval_split(self.cfg, self.data,
                                                         split)
@@ -142,8 +153,10 @@ class SwarmTrainer:
     def round(self, r: int, key=None) -> RoundLog:
         """Round ``r``, one engine dispatch; ``key`` starts a key chain
         (None continues the state's). Traced as the host span ``bso.round``
-        (step ``r``) holding ``bso.dispatch`` and ``bso.round_log``."""
-        with jax.profiler.StepTraceAnnotation("bso.round", step_num=r):
+        (step ``r``, with the static ``eval_rows`` and ``eval_real_rows``)
+        holding ``bso.dispatch`` and ``bso.round_log``."""
+        with jax.profiler.StepTraceAnnotation("bso.round", step_num=r,
+                                              **self._round_args):
             if key is not None:   # copied: the engine donates the state
                 self.state = self.state._replace(key=jnp.copy(jnp.asarray(key)))
             with jax.profiler.TraceAnnotation("bso.dispatch"):

@@ -29,7 +29,7 @@ from repro.configs import get_config
 from repro.configs.base import OptimizerConfig
 from repro.core.bso import brain_storm
 from repro.core.engine import (EngineConfig, jit_run_rounds, make_swarm_data,
-                               make_swarm_state)
+                               make_swarm_state, stack_eval_split)
 from repro.core.kmeans import kmeans
 from repro.data.dr import TABLE_I, make_dr_swarm_data
 from repro.launch.fleet_driver import (host_coordinator, make_unit_fleet,
@@ -133,7 +133,7 @@ def test_fleet_round_donated_reuse_does_not_retrace(unit_model,
         sparams = jax.device_put(jax.vmap(unit_model.init)(keys), psh)
         sopt = jax.device_put(jax.vmap(opt.init)(sparams), osh)
         val = jax.device_put(
-            make_swarm_data(unit_model.cfg, unit_clients).val, vsh)
+            stack_eval_split(unit_model.cfg, unit_clients, "val"), vsh)
         weights = jax.device_put(
             jnp.asarray([c["n_train"] for c in unit_clients], jnp.float32),
             wsh)

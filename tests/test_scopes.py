@@ -83,7 +83,14 @@ def test_fit_rounds_are_host_spans(trainer, tmp_path):
     rounds = sorted((e for e in host if e.name == "bso.round"),
                     key=lambda e: e.start_ns)
     assert [dict(e.stats)["step_num"] for e in rounds] == [start, start + 1]
+    # the static eval row counts of the layout, on every round
+    real = sum(len(c["val"][1]) for c in trainer.data)
+    slots = sum(int(np.prod(v["labels"].shape[:3]))
+                for v in trainer.swarm_data.val)
+    assert 0 < real <= slots
     for r in rounds:
+        stats = dict(r.stats)
+        assert (stats["eval_rows"], stats["eval_real_rows"]) == (slots, real)
         inside = [e.name for e in host
                   if r.start_ns <= e.start_ns
                   and e.start_ns + e.duration_ns <= r.start_ns + r.duration_ns]
